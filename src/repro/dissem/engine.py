@@ -97,12 +97,13 @@ def stability_tick_dense(state: DissemState, holds: jax.Array, *,
                    static_argnames=("majority", "block_w", "interpret"))
 def stability_tick_fused(state: DissemState, packed: jax.Array, *,
                          majority: int, block_w: int = 256,
-                         interpret: bool = True)\
+                         interpret: bool = False)\
         -> tuple[DissemState, dict]:
     """Same pass through the fused Pallas kernel
     (``repro.kernels.dissem``): one 2-D-grid launch absorbs every group
-    and also reduces the per-group newly-stable count on-chip. Interpret
-    mode on CPU; ``interpret=False`` on a TPU runtime."""
+    and also reduces the per-group newly-stable count on-chip.
+    ``interpret=True`` runs the kernel body in Python (the CPU test
+    path)."""
     from ..kernels.dissem import stability_update_grouped
     bits, counts, stable, newly = stability_update_grouped(
         state.hold_bits, packed, state.stable, majority=majority,

@@ -62,24 +62,11 @@ from . import sharded as sharded_mod
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """Version-portable shard_map with replication checking off.
-
-    Prefers the top-level ``jax.shard_map`` (newer jax; avoids the
-    deprecation warning on ``jax.experimental``), falling back through
-    the ``check_vma``/``check_rep`` keyword rename to the experimental
-    module (jax 0.4.x).  Replication checking must be off: the merge
-    replica is rebuilt from ``all_gather`` results, which the checker
-    cannot prove replicated."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    for kw in ("check_vma", "check_rep"):
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{kw: False})
-        except TypeError:
-            continue
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """``jax.shard_map`` with replication checking off: the merge replica
+    is rebuilt from ``all_gather`` results, which the checker cannot
+    prove replicated."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @functools.lru_cache(maxsize=None)

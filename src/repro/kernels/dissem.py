@@ -21,10 +21,8 @@ revisit the same output row consecutively). The gating layer
 (``repro.engine.sharded`` gated ticks) uses it as its cheap "did any id
 become orderable this tick" signal without a second host-side pass.
 
-Validated in interpret mode on CPU (how this container runs it); pass
-``interpret=False`` on a TPU runtime. Block sizing reuses
-``quorum._pick_block_w`` so any window shape launches without caller-side
-padding.
+Block sizing and BlockSpecs are shared with ``repro.kernels.quorum``,
+so any window shape launches without caller-side padding.
 """
 from __future__ import annotations
 
@@ -34,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .quorum import DEFAULT_BLOCK_W, _pick_block_w
+from .quorum import DEFAULT_BLOCK_W, _grouped_specs, _pick_block_w, _rows
 
 
 def _stability_kernel(bits_ref, update_ref, stable_in_ref,
@@ -63,33 +61,30 @@ def _stability_kernel(bits_ref, update_ref, stable_in_ref,
 def stability_update_grouped(bits: jax.Array, update: jax.Array,
                              stable: jax.Array, *, majority: int,
                              block_w: int = DEFAULT_BLOCK_W,
-                             interpret: bool = True):
+                             interpret: bool = False):
     """bits/update: uint32[G, W, WORDS]; stable: bool[G, W].
     Returns (new_bits, counts int32[G, W], new_stable bool[G, W],
-    newly int32[G] — ids crossing the majority threshold this call)."""
+    newly int32[G] — ids crossing the majority threshold this call).
+    ``interpret=True`` runs the kernel body in Python (the CPU test
+    path)."""
     G, W, WORDS = bits.shape
     block_w = _pick_block_w(W, block_w)
-    grid = (G, W // block_w)
+    tile, row = _grouped_specs(block_w, WORDS)
     kernel = functools.partial(_stability_kernel, majority=majority)
-    return pl.pallas_call(
+    bits, counts, stable, newly = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_w, WORDS), lambda g, i: (g, i, 0)),
-            pl.BlockSpec((1, block_w, WORDS), lambda g, i: (g, i, 0)),
-            pl.BlockSpec((1, block_w), lambda g, i: (g, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_w, WORDS), lambda g, i: (g, i, 0)),
-            pl.BlockSpec((1, block_w), lambda g, i: (g, i)),
-            pl.BlockSpec((1, block_w), lambda g, i: (g, i)),
-            pl.BlockSpec((1,), lambda g, i: (g,)),
-        ],
+        grid=(G, W // block_w),
+        in_specs=[tile, tile, row],
+        # newly: one (1, 1) block per group, its last two dimensions
+        # whole, revisited by all of the group's window blocks in turn
+        out_specs=[tile, row, row,
+                   pl.BlockSpec((None, 1, 1), lambda g, i: (g, 0, 0))],
         out_shape=[
             jax.ShapeDtypeStruct((G, W, WORDS), jnp.uint32),
-            jax.ShapeDtypeStruct((G, W), jnp.int32),
-            jax.ShapeDtypeStruct((G, W), jnp.bool_),
-            jax.ShapeDtypeStruct((G,), jnp.int32),
+            jax.ShapeDtypeStruct((G, 1, W), jnp.int32),
+            jax.ShapeDtypeStruct((G, 1, W), jnp.bool_),
+            jax.ShapeDtypeStruct((G, 1, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(bits, update, stable)
+    )(bits, update, _rows(stable))
+    return bits, counts[:, 0], stable[:, 0], newly[:, 0, 0]

@@ -93,7 +93,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = -1,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """q: [B,Sq,H,h]; k/v: [B,Skv,K,h|hv]; GQA via H = K·G. Returns
     [B,Sq,H,hv]."""
     B, Sq, H, h = q.shape

@@ -64,7 +64,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state_scr,
 @functools.partial(jax.jit,
                    static_argnames=("chunk", "interpret"))
 def wkv6_chunked(r, k, v, wlog, u, *, chunk: int = 128,
-                 interpret: bool = True):
+                 interpret: bool = False):
     """r/k/v/wlog: [B,S,H,hd] (wlog = log decay, f32-representable);
     u: [H, hd] bonus. Returns [B,S,H,hd] f32 WKV output (pre-gate)."""
     B, S, H, hd = r.shape

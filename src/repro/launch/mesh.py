@@ -43,7 +43,11 @@ def make_group_mesh(n_groups: int, *, n_devices: int | None = None,
     avail = len(jax.devices())
     n = avail if n_devices is None else min(int(n_devices), avail)
     n = max(1, min(n, int(n_groups)))
-    return jax.make_mesh((n,), (axis_name,))
+    # Auto, not make_mesh's Explicit default: the meshed engine returns
+    # group-sharded arrays to callers who reshape them freely (the merge
+    # commit gate flattens [L, G] logs), which Explicit typing refuses
+    return jax.make_mesh((n,), (axis_name,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def group_padding(n_groups: int, mesh) -> int:

@@ -433,20 +433,15 @@ def plan_admissions(cfg: PipelineConfig, workload: Workload,
                           "rank": len(admits[g])})
 
     for t in range(T):
-        flushes = []                      # (d, kind-order) within the tick
-        tails = []
+        closed = [0] * D                  # budget closures per lane
         for c in np.nonzero(arrived[t])[0]:
             d = int(c) % D
             if accs[d].add(int(sizes[t, c])) is not None:
-                flushes.append(d)
-        for d in range(D):
-            if accs[d].flush() is not None:
-                tails.append(d)
+                closed[d] += 1
         # jit order: lane-major, overflow closures before the lane's tail
         for d in range(D):
-            for fd in flushes:
-                if fd == d:
-                    admit(d, t)
-            if d in tails:
+            for _ in range(closed[d]):
+                admit(d, t)
+            if accs[d].flush() is not None:
                 admit(d, t)
     return admits

@@ -100,7 +100,9 @@ class TestStabilityEngine:
         assert (np.asarray(stable_ids(st0, ids)) == -1).all()
 
     @pytest.mark.parametrize("G,W,n,block_w", [
-        (1, 8, 5, 8), (2, 24, 5, 8), (3, 16, 37, 4), (2, 10, 33, 256)])
+        (1, 8, 5, 8), (2, 24, 5, 8), (3, 16, 37, 4), (2, 10, 33, 256),
+        # several 128-lane window blocks per group: `newly` accumulates
+        (2, 256, 37, 128), (1, 384, 5, 128)])
     def test_fused_kernel_matches_reference(self, G, W, n, block_w):
         rng = np.random.default_rng(G * 100 + W)
         packed = _rand_packed(rng, 2, G, W, n)
@@ -111,9 +113,10 @@ class TestStabilityEngine:
         ref, oref = stability_tick(ref0, jnp.asarray(packed[1]), majority=maj)
         fus0, _ = stability_tick_fused(init_dissem(G, W, n),
                                        jnp.asarray(packed[0]), majority=maj,
-                                       block_w=block_w)
+                                       block_w=block_w, interpret=True)
         fus, ofus = stability_tick_fused(fus0, jnp.asarray(packed[1]),
-                                         majority=maj, block_w=block_w)
+                                         majority=maj, block_w=block_w,
+                                         interpret=True)
         assert (np.asarray(ref.hold_bits) == np.asarray(fus.hold_bits)).all()
         assert (np.asarray(ref.stable) == np.asarray(fus.stable)).all()
         assert (np.asarray(oref["counts"]) == np.asarray(ofus["counts"])).all()

@@ -96,8 +96,8 @@ def test_quorum_kernel_grouped_edge_shapes_vs_packed_core(G, W, D):
 
 
 def test_quorum_kernel_single_group_odd_window():
-    """1-D launch at a non-dividing block size: block_w falls back to the
-    largest divisor of W instead of asserting."""
+    """1-D launch at a non-dividing block size: a window with no
+    128-lane divisor runs as one block instead of asserting."""
     W, D = 40, 100
     words = (D + 31) // 32
     rng = np.random.default_rng(40)
@@ -105,7 +105,7 @@ def test_quorum_kernel_single_group_odd_window():
     upd = jnp.asarray(rng.integers(0, 2**32, (W, words), dtype=np.uint32))
     stable = jnp.zeros((W,), jnp.bool_)
     got = quorum_update(bits, upd, stable, majority=D // 2 + 1,
-                        block_w=16, interpret=True)   # 16 ∤ 40 → block 8
+                        block_w=16, interpret=True)   # 16 ∤ 40 → one block
     want = ref.quorum_ref(bits, upd, stable, majority=D // 2 + 1)
     for g, w in zip(got, want):
         assert np.array_equal(np.asarray(g), np.asarray(w))

@@ -282,8 +282,11 @@ def device_runs():
     src = Path(__file__).resolve().parent.parent / "src"
     runs = {}
     for ndev in (1, 8):
+        # the children emulate devices on the host CPU and must never
+        # reach for an accelerator the parent may hold
         env = dict(
             os.environ,
+            JAX_PLATFORMS="cpu",
             XLA_FLAGS=f"--xla_force_host_platform_device_count={ndev}",
             PYTHONPATH=str(src) + os.pathsep + os.environ.get(
                 "PYTHONPATH", ""))

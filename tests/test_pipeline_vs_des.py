@@ -1,167 +1,37 @@
 """Closed-pipeline cross-validation: jax pipeline vs DES, shared workload.
 
 The strongest cross-check in the suite: the SAME pre-drawn workload
-arrays drive both the closed in-jax pipeline
-(``repro.pipeline.closed``) and the discrete-event simulator
-(``HTPaxosSim`` via ``HTConfig.workload_schedule``), and both must
-produce the identical learner batch order. Neither side is derived
-from the other's trace — unlike ``test_engine_vs_des*``, which replay
-DES-extracted tiles — so this validates the whole chain: client→lane
-assignment, byte-budget batching, bid sequencing, epoch routing,
-stability gating, ordering, and the round-robin merge.
-
-Alignment construction (what makes bit-equality *provable* rather than
-coincidental): time is cut into cycles of the DES skip period P; each
-cycle either injects exactly one batch per active ordering group
-(covering lanes found greedily against the shared crc32 router) or
-nothing at all. Batches are injected 4 ticks before the next skip-timer
-fire, so every active group's leader has the proposal in flight at the
-fire and never no-ops; idle/inactive rows no-op exactly once per cycle.
-Every row therefore advances exactly one rank per non-quiet cycle on
-the DES side, while the engine's SKIP padding (``entries_from_assigned``
-pads all rows to the per-tick max) enforces the same rank alignment on
-the jax side — so after dropping control entries, both round-robin
-merges interleave the real batches identically: cycle by cycle,
-ascending group index. A mid-run membership switch stays aligned
-because both sides charge the epoch marker one rank in every row.
+arrays drive both the closed in-jax pipeline and ``HTPaxosSim``, and
+both must produce the identical learner batch order. The alignment
+construction that makes bit-equality provable lives in
+:mod:`repro.pipeline.crosscheck`, where ``chip_smoke.py`` reuses it.
 """
 from __future__ import annotations
 
-import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-from repro.core.htpaxos import HTConfig, HTPaxosSim  # noqa: E402
-from repro.core.classic import OrderingConfig  # noqa: E402
-from repro.engine.api import (EngineConfig, GatingConfig,  # noqa: E402
-                              RecyclingConfig)
-from repro.engine.epochs import EpochTable, route_id_epoch  # noqa: E402
-from repro.pipeline import (PipelineConfig, Workload,  # noqa: E402
-                            build_route_table, committed, decode_merged,
-                            init_pipeline, pipeline_tick_jit, run_pipeline,
+from repro.engine.epochs import EpochTable  # noqa: E402
+from repro.pipeline import (build_route_table, committed,  # noqa: E402
+                            decode_merged, init_pipeline, run_pipeline,
                             reconfigure_pipeline)
-
-P = 8           # DES skip period = one alignment cycle
-BUDGET = 4096   # byte budget: roomy, so one flush = one batch
-
-
-def greedy_cover_schedule(n_lanes, actives, epochs, table):
-    """Per cycle, pick one lane per active group whose *next* bid routes
-    there (each lane used at most once per cycle). Returns
-    [(cycle, lane, seq, group), ...]; raises if no cover exists — the
-    construction is deterministic, so a config that builds once builds
-    forever."""
-    seqs = [0] * n_lanes
-    plan = []
-    for cyc, (active, ep) in enumerate(zip(actives, epochs)):
-        owners = {d: route_id_epoch((f"d{d}", seqs[d]), table, ep)
-                  for d in range(n_lanes)}
-        used = set()
-        for g in active:
-            cand = [d for d in range(n_lanes)
-                    if owners[d] == g and d not in used]
-            if not cand:
-                raise AssertionError(
-                    f"cover construction stuck at cycle {cyc} for group "
-                    f"{g}: next bids route to {owners}")
-            d = cand[0]
-            used.add(d)
-            plan.append((cyc, d, seqs[d], g))
-            seqs[d] += 1
-    return plan
-
-
-def make_workload(plan, n_cycles, n_lanes, n_clients):
-    """Workload arrays from a cover plan: batch (cycle, lane) becomes a
-    request from client=lane at tick=cycle; every third cycle one lane
-    also gets a second request from client lane+n_lanes (same lane, so
-    the two requests share one batch — exercising multi-request
-    batches without disturbing the one-batch-per-group cover)."""
-    events = []
-    for i, (cyc, lane, _seq, _g) in enumerate(plan):
-        size = 200 + 37 * ((7 * cyc + 13 * lane) % 20)
-        events.append((cyc, lane, size))
-        if i % 3 == 0 and n_clients >= n_lanes + lane + 1:
-            events.append((cyc, n_lanes + lane,
-                           150 + 29 * (cyc % 11)))
-    return Workload.from_schedule(events, ticks=n_cycles,
-                                  n_clients=n_clients)
-
-
-def pipeline_cfg(G, D, *, table=None, capacity=256):
-    return PipelineConfig(
-        engine=EngineConfig(
-            groups=G, window=8, n_diss=D, n_seq=3, order_budget=4,
-            merge_capacity=G * 512,
-            recycling=RecyclingConfig(watermark=4, id_stride=4096),
-            gating=GatingConfig(stab_majority=D // 2 + 1,
-                                n_diss_partition=D),
-            epochs=table),
-        n_clients=2 * D, budget_bytes=BUDGET,
-        capacity=capacity, seq_capacity=64)
-
-
-def drain(pcfg, st, rt, max_ticks=24):
-    empty_a = jnp.zeros((pcfg.n_clients,), bool)
-    empty_s = jnp.zeros((pcfg.n_clients,), jnp.int32)
-    for _ in range(max_ticks):
-        st, _ = pipeline_tick_jit(pcfg, st, empty_a, empty_s, rt)
-        _, count, com = committed(pcfg, st)
-        if int(com) == int(st.admit_count.sum()):
-            break
-    return st
-
-
-def des_schedule(workload):
-    """Map workload ticks to DES times: tick k → kP + (P-4), so the
-    proposal is in flight at the next skip fire (see module docstring)."""
-    return tuple((cyc * P + (P - 4.0), client, size)
-                 for (cyc, client, size) in workload.schedule())
-
-
-def run_des(G, D, workload, *, reconfig=None, until):
-    cfg = HTConfig(
-        n_diss=D, n_seq=3, n_clients=2 * D,
-        batch_budget_bytes=BUDGET, random_client_target=False,
-        n_groups=G, group_skip_interval=float(P),
-        ordering=OrderingConfig(order_batch_max=1),
-        reconfig_schedule=reconfig or (),
-        workload_schedule=des_schedule(workload))
-    sim = HTPaxosSim(cfg, requests_per_client=0)
-    sim.run(until=until)
-    assert sim.check_merged_interleaving() == []
-    orders = [list(a.executed_bid_order) for a in sim.all_learner_agents()]
-    assert all(o == orders[0] for o in orders), \
-        "DES learners diverged among themselves"
-    return sim, orders[0]
+from repro.pipeline.crosscheck import (P, cover_pipeline_config,  # noqa: E402
+                                       cover_workload, drain,
+                                       greedy_cover_schedule,
+                                       pipeline_vs_des, run_des)
 
 
 @pytest.mark.parametrize("G,D", [(1, 5), (2, 10), (4, 12)])
 def test_closed_pipeline_matches_des(G, D):
-    n_cycles = 12
-    table = EpochTable((tuple(range(G)),), n_rows=G)
-    plan = greedy_cover_schedule(
-        D, [tuple(range(G))] * n_cycles, [0] * n_cycles, table)
-    wl = make_workload(plan, n_cycles, D, 2 * D)
-
-    pcfg = pipeline_cfg(G, D)
-    rt = jnp.asarray(build_route_table(pcfg))
-    st = init_pipeline(pcfg)
-    st, outs = run_pipeline(pcfg, st, wl.arrived, wl.sizes, rt)
-    st = drain(pcfg, st, rt)
-    assert not bool(st.overflowed)
-    assert int(outs["dropped"].sum()) == 0
-    merged, count, com = committed(pcfg, st)
-    n_adm = int(st.admit_count.sum())
-    assert n_adm == len(plan)
-    assert int(com) == n_adm, "pipeline failed to drain"
-    jax_order = decode_merged(pcfg, st, merged, com)
-
-    _, des_order = run_des(G, D, wl, until=n_cycles * P + 20)
-    assert len(des_order) == len(plan)
-    assert jax_order == des_order
+    r = pipeline_vs_des(G, D)
+    assert not r["overflowed"]
+    assert r["dropped"] == 0
+    assert r["admitted"] == len(r["plan"])
+    assert r["committed"] == r["admitted"], "pipeline failed to drain"
+    assert len(r["des_order"]) == len(r["plan"])
+    assert r["jax_order"] == r["des_order"]
 
 
 def test_closed_pipeline_matches_des_reconfig():
@@ -172,9 +42,9 @@ def test_closed_pipeline_matches_des_reconfig():
     table = EpochTable(((0, 1), (0,)), n_rows=G)
     plan = greedy_cover_schedule(
         D, [(0, 1)] * k0 + [(0,)] * k1, [0] * k0 + [1] * k1, table)
-    wl = make_workload(plan, n_cycles, D, 2 * D)
+    wl = cover_workload(plan, n_cycles, D, 2 * D)
 
-    pcfg = pipeline_cfg(G, D, table=table)
+    pcfg = cover_pipeline_config(G, D, table=table)
     rt0 = jnp.asarray(build_route_table(pcfg, epoch=0))
     rt1 = jnp.asarray(build_route_table(pcfg, epoch=1))
     st = init_pipeline(pcfg)
