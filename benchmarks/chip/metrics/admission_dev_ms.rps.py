@@ -1,0 +1,9 @@
+"""Admission (``ht.admission``): flushed batches routed, ranked per group and
+scattered into the admission records. Device ms per arrival tick of the
+traced segment: the ops of ``run_pipeline``'s module whose innermost stage
+scope is this one (``scopes.py``), over the segment's arrival ticks."""
+from scopes import stage_ms_per_tick
+
+
+def read(run):
+    return stage_ms_per_tick(run, "ht.admission")

@@ -1,0 +1,9 @@
+"""Stability (``ht.stability``): hold tiles absorbed, stability quorum of each
+group's partition. Device ms per arrival tick of the traced segment: the
+ops of ``run_pipeline``'s module whose innermost stage scope is this one
+(``scopes.py``), over the segment's arrival ticks."""
+from scopes import stage_ms_per_tick
+
+
+def read(run):
+    return stage_ms_per_tick(run, "ht.stability")

@@ -75,7 +75,6 @@ import jax.numpy as jnp
 from ..core import jaxsim
 from ..core.jaxsim import admitted_mask
 from ..dissem import engine as dissem_engine
-from ..dissem.engine import absorb_holds_packed
 from . import merge as merge_mod
 from . import sharded as sharded_mod
 
@@ -329,12 +328,19 @@ def _family_tick(cfg, core, dissem, slot_ids, acks, votes, holds,
     vtick = jax.vmap(functools.partial(
         jaxsim.engine_tick_packed, diss_majority=cfg.diss_majority,
         seq_majority=cfg.seq_majority, order_budget=cfg.order_budget))
+
+    def gated_step(q, d):
+        return sharded_mod._gated_step(
+            q, d, acks, holds, votes, diss_majority=cfg.diss_majority,
+            seq_majority=cfg.seq_majority,
+            stab_majority=cfg.gating.stab_majority,
+            order_budget=cfg.order_budget)
+
     if fam == "plain":
         q, out = vtick(core, acks, votes)
         return q, None, out["assigned"], slot_ids
     if fam == "gated":
-        d, _ = absorb_holds_packed(dissem, holds, cfg.gating.stab_majority)
-        q, out = vtick(core, acks, sharded_mod._gated_votes(d, votes))
+        q, d, out = gated_step(core, dissem)
         return q, d, out["assigned"], slot_ids
     if fam == "recycled":
         q, out = vtick(core.q, acks, votes)
@@ -346,9 +352,7 @@ def _family_tick(cfg, core, dissem, slot_ids, acks, votes, holds,
             id_stride=cfg.recycling.id_stride, id_base=id_base)
         return rs, None, out["assigned"], sids
     # gated_recycled
-    d, _ = absorb_holds_packed(core.d, holds, cfg.gating.stab_majority)
-    q, out = vtick(core.rs.q, acks,
-                   sharded_mod._gated_votes(d, votes))
+    q, d, out = gated_step(core.rs.q, core.d)
     sids = core.rs.slot_ids
     gs = sharded_mod.GatedRecycleState(
         rs=sharded_mod.RecycleState(q=q, slot_ids=sids,

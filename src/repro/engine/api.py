@@ -56,6 +56,7 @@ from . import adaptive as adaptive_mod
 from . import epochs as epochs_mod
 from . import merge as merge_mod
 from . import sharded as sharded_mod
+from . import stages
 from .adaptive import AdaptiveConfig
 from .epochs import EpochTable
 
@@ -308,6 +309,30 @@ def slot_ids(state: EngineState) -> jax.Array:
     return core.slot_ids
 
 
+def progress(cfg: EngineConfig, state: EngineState) -> dict:
+    """Running totals over all groups, int32 scalars: ``ordered``
+    (instances assigned), ``decided`` (ids decided on the commit quorum)
+    and, for gated families, ``stable`` (ids stable). A retired id counts
+    as decided and stable: only decided ids retire, and a decided id
+    passed the stability gate. The difference of two readings counts
+    what the steps between them did, on every path that keeps this state
+    (facade, adaptive, meshed). On a meshed config the state is sharded by
+    group, so each sum reduces across the mesh."""
+    if cfg.recycling is not None:
+        rs = state.core.rs if cfg.family == "gated_recycled" \
+            else state.core
+        q, retired = rs.q, rs.retired.sum(dtype=jnp.int32)
+    else:
+        q, retired = state.core, jnp.int32(0)
+    out = {"ordered": q.next_instance.sum(dtype=jnp.int32),
+           "decided": q.decided.sum(dtype=jnp.int32) + retired}
+    if cfg.gating is not None:
+        d = state.core.d if cfg.family == "gated_recycled" \
+            else state.dissem
+        out["stable"] = d.stable.sum(dtype=jnp.int32) + retired
+    return out
+
+
 def _need_holds(cfg: EngineConfig, holds) -> None:
     if (cfg.gating is not None) == (holds is None):
         raise ValueError(
@@ -479,15 +504,17 @@ def committed_prefix(cfg: EngineConfig, state: EngineState)\
     """(merged, merged_count, committed_count) of the current state,
     without ticking — the recycle-aware commit gate for recycled
     families, the live-window gate otherwise."""
-    if cfg.recycling is not None:
-        rs = state.core.rs if cfg.family == "gated_recycled" \
-            else state.core
-        return sharded_mod.recycled_committed_prefix(rs, state.merge)
-    merged, count = merge_mod.merged_prefix(state.merge)
-    dec = sharded_mod._decided_by_instance(
-        state.core.instance, state.core.decided, state.merge.logs.shape[1])
-    committed = merge_mod.committed_prefix_len(state.merge, dec)
-    return merged, count, committed
+    with jax.named_scope(stages.COMMIT_GATE):
+        if cfg.recycling is not None:
+            rs = state.core.rs if cfg.family == "gated_recycled" \
+                else state.core
+            return sharded_mod.recycled_committed_prefix(rs, state.merge)
+        merged, count = merge_mod.merged_prefix(state.merge)
+        dec = sharded_mod._decided_by_instance(
+            state.core.instance, state.core.decided,
+            state.merge.logs.shape[1])
+        committed = merge_mod.committed_prefix_len(state.merge, dec)
+        return merged, count, committed
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))

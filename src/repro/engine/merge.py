@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import stages
+
 SKIP = -2   # explicit null instance: holds a round-robin slot, never emitted
 PAD = -1    # padding in fixed-shape outputs / unwritten log tail
 RECONFIG = -3  # epoch-boundary marker (repro.engine.epochs): holds one
@@ -73,21 +75,23 @@ def append_entries(state: MergeState, entries: jax.Array,
     ``state.overflowed`` so callers (and the run_* debug asserts) can
     detect an undersized log instead of consuming a corrupted order.
     """
-    G, L = state.logs.shape
-    K = entries.shape[1]
-    j = jnp.arange(L, dtype=jnp.int32)[None, :]                  # [1, L]
-    rel = j - state.watermarks[:, None]                          # [G, L]
-    take = (rel >= 0) & (rel < counts[:, None])
-    gathered = jnp.take_along_axis(
-        entries, jnp.clip(rel, 0, K - 1), axis=1)
-    logs = jnp.where(take, gathered, state.logs)
-    counts = counts.astype(jnp.int32)
-    # entries whose cell index wm+k lands at or past L (watermark may
-    # already exceed L from earlier overflow, hence the clip to [0, counts])
-    over = jnp.clip(state.watermarks + counts - jnp.int32(L), 0, counts)
-    return MergeState(logs=logs,
-                      watermarks=state.watermarks + counts,
-                      overflowed=state.overflowed + over)
+    with jax.named_scope(stages.MERGE_APPEND):
+        G, L = state.logs.shape
+        K = entries.shape[1]
+        j = jnp.arange(L, dtype=jnp.int32)[None, :]                  # [1, L]
+        rel = j - state.watermarks[:, None]                          # [G, L]
+        take = (rel >= 0) & (rel < counts[:, None])
+        gathered = jnp.take_along_axis(
+            entries, jnp.clip(rel, 0, K - 1), axis=1)
+        logs = jnp.where(take, gathered, state.logs)
+        counts = counts.astype(jnp.int32)
+        # entries whose cell index wm+k lands at or past L (watermark may
+        # already exceed L from earlier overflow, hence the clip to
+        # [0, counts])
+        over = jnp.clip(state.watermarks + counts - jnp.int32(L), 0, counts)
+        return MergeState(logs=logs,
+                          watermarks=state.watermarks + counts,
+                          overflowed=state.overflowed + over)
 
 
 def mergeable_counts(watermarks: jax.Array) -> jax.Array:
@@ -156,18 +160,20 @@ def entries_from_assigned(assigned: jax.Array, slot_ids: jax.Array,
     is unchanged: skip tokens are per-*position* round-robin fillers and
     never refer to slots, so recycling cannot invalidate them.
     """
-    mask = assigned >= 0                                         # [G, W]
-    pos = jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1         # [G, W]
-    n_assigned = jnp.sum(mask, axis=1, dtype=jnp.int32)          # [G]
-    entries = jnp.full((assigned.shape[0], max_entries), SKIP, jnp.int32)
-    entries = jax.vmap(
-        lambda e, p, m, ids: e.at[jnp.where(m, p, max_entries)].set(
-            ids, mode="drop"))(entries, pos, mask, slot_ids.astype(jnp.int32))
-    counts = jnp.broadcast_to(
-        jnp.minimum(jnp.max(n_assigned), max_entries), n_assigned.shape)
-    dropped = jnp.sum(jnp.maximum(n_assigned - max_entries, 0),
-                      dtype=jnp.int32)
-    return entries, counts, dropped
+    with jax.named_scope(stages.MERGE_APPEND):
+        mask = assigned >= 0                                         # [G, W]
+        pos = jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1         # [G, W]
+        n_assigned = jnp.sum(mask, axis=1, dtype=jnp.int32)          # [G]
+        entries = jnp.full((assigned.shape[0], max_entries), SKIP, jnp.int32)
+        entries = jax.vmap(
+            lambda e, p, m, ids: e.at[jnp.where(m, p, max_entries)].set(
+                ids, mode="drop"))(entries, pos, mask,
+                                   slot_ids.astype(jnp.int32))
+        counts = jnp.broadcast_to(
+            jnp.minimum(jnp.max(n_assigned), max_entries), n_assigned.shape)
+        dropped = jnp.sum(jnp.maximum(n_assigned - max_entries, 0),
+                          dtype=jnp.int32)
+        return entries, counts, dropped
 
 
 def round_entries(assigned: jax.Array, slot_ids: jax.Array,
@@ -193,16 +199,17 @@ def round_entries(assigned: jax.Array, slot_ids: jax.Array,
     dropped int32[G] — ids past ``round_width``, zero whenever
     ``round_width ≥ order_budget``).
     """
-    mask = assigned >= 0                                         # [G, W]
-    pos = jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1         # [G, W]
-    n_assigned = jnp.sum(mask, axis=1, dtype=jnp.int32)          # [G]
-    entries = jnp.full((assigned.shape[0], round_width), SKIP, jnp.int32)
-    entries = jax.vmap(
-        lambda e, p, m, ids: e.at[jnp.where(m, p, round_width)].set(
-            ids, mode="drop"))(entries, pos, mask,
-                               slot_ids.astype(jnp.int32))
-    dropped = jnp.maximum(n_assigned - round_width, 0)
-    return entries, n_assigned, dropped
+    with jax.named_scope(stages.MERGE_APPEND):
+        mask = assigned >= 0                                         # [G, W]
+        pos = jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1         # [G, W]
+        n_assigned = jnp.sum(mask, axis=1, dtype=jnp.int32)          # [G]
+        entries = jnp.full((assigned.shape[0], round_width), SKIP, jnp.int32)
+        entries = jax.vmap(
+            lambda e, p, m, ids: e.at[jnp.where(m, p, round_width)].set(
+                ids, mode="drop"))(entries, pos, mask,
+                                   slot_ids.astype(jnp.int32))
+        dropped = jnp.maximum(n_assigned - round_width, 0)
+        return entries, n_assigned, dropped
 
 
 def committed_prefix_len(state: MergeState,

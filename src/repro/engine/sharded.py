@@ -41,6 +41,7 @@ from ..core import jaxsim
 from ..core.jaxsim import QuorumState
 from ..dissem.engine import DissemState, absorb_holds_packed, init_dissem
 from . import merge as merge_mod
+from . import stages
 
 
 def init_sharded(groups: int, window: int, n_diss: int, n_seq: int)\
@@ -276,23 +277,25 @@ def recycle_groups(rs: RecycleState, *, watermark: int, id_stride: int,
     logical group 0, and fresh ids must come from the logical group's
     private range no matter which device owns the row.
     """
-    G = rs.slot_ids.shape[0]
-    free = jnp.sum(~rs.q.decided, axis=1, dtype=jnp.int32)
-    head_retirable = jnp.any(
-        (rs.q.instance == rs.retired[:, None]) & rs.q.decided, axis=1)
-    enable = (free < watermark) & head_retirable
-    if id_base is None:
-        id_base = jnp.arange(G, dtype=jnp.int32) * id_stride
+    with jax.named_scope(stages.RECYCLE):
+        G = rs.slot_ids.shape[0]
+        free = jnp.sum(~rs.q.decided, axis=1, dtype=jnp.int32)
+        head_retirable = jnp.any(
+            (rs.q.instance == rs.retired[:, None]) & rs.q.decided, axis=1)
+        enable = (free < watermark) & head_retirable
+        if id_base is None:
+            id_base = jnp.arange(G, dtype=jnp.int32) * id_stride
 
-    def compact(rs):
-        q, ids, retired, n_ret = jax.vmap(jaxsim.compact_and_refill_packed)(
-            rs.q, rs.slot_ids, rs.retired, id_base, enable)
-        return RecycleState(q=q, slot_ids=ids, retired=retired), n_ret
+        def compact(rs):
+            q, ids, retired, n_ret = jax.vmap(
+                jaxsim.compact_and_refill_packed)(
+                rs.q, rs.slot_ids, rs.retired, id_base, enable)
+            return RecycleState(q=q, slot_ids=ids, retired=retired), n_ret
 
-    def skip(rs):
-        return rs, jnp.zeros((G,), jnp.int32)
+        def skip(rs):
+            return rs, jnp.zeros((G,), jnp.int32)
 
-    return jax.lax.cond(jnp.any(enable), compact, skip, rs)
+        return jax.lax.cond(jnp.any(enable), compact, skip, rs)
 
 
 def recycled_committed_prefix(rs: RecycleState,
@@ -431,6 +434,25 @@ def _gated_votes(d: DissemState, packed_votes: jax.Array) -> jax.Array:
     return jnp.where(d.stable[..., None], packed_votes, jnp.uint32(0))
 
 
+def _gated_step(q: QuorumState, d: DissemState, packed_acks: jax.Array,
+                packed_holds: jax.Array, packed_votes: jax.Array, *,
+                diss_majority: int, seq_majority: int, stab_majority: int,
+                order_budget: int | None)\
+        -> tuple[QuorumState, DissemState, dict]:
+    """The gated families' shared step, merge and recycling aside: absorb
+    the holds (stability), then tick every group's window with its votes
+    masked until stable (ordering). Returns (q, d, out) with
+    ``engine_tick_packed``'s outputs plus out["newly_stable"] bool[G, W]."""
+    with jax.named_scope(stages.STABILITY):
+        d, dout = absorb_holds_packed(d, packed_holds, stab_majority)
+    with jax.named_scope(stages.ORDERING):
+        q, out = jax.vmap(functools.partial(
+            jaxsim.engine_tick_packed, diss_majority=diss_majority,
+            seq_majority=seq_majority, order_budget=order_budget))(
+            q, packed_acks, _gated_votes(d, packed_votes))
+    return q, d, dict(out, newly_stable=dout["newly_stable"])
+
+
 @functools.partial(jax.jit, static_argnames=(
     "diss_majority", "seq_majority", "stab_majority", "order_budget"))
 def gated_tick(state: QuorumState, d: DissemState, packed_acks: jax.Array,
@@ -447,12 +469,11 @@ def gated_tick(state: QuorumState, d: DissemState, packed_acks: jax.Array,
     gate adds no latency beyond the dissemination itself. Returns
     (state, d, out) with the ungated tick's outputs plus
     out["newly_stable"] bool[G, W]."""
-    d, dout = absorb_holds_packed(d, packed_holds, stab_majority)
-    state, out = jax.vmap(functools.partial(
-        jaxsim.engine_tick_packed, diss_majority=diss_majority,
-        seq_majority=seq_majority, order_budget=order_budget))(
-        state, packed_acks, _gated_votes(d, packed_votes))
-    return state, d, dict(out, newly_stable=dout["newly_stable"])
+    return _gated_step(state, d, packed_acks, packed_holds, packed_votes,
+                       diss_majority=diss_majority,
+                       seq_majority=seq_majority,
+                       stab_majority=stab_majority,
+                       order_budget=order_budget)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -473,15 +494,15 @@ def run_gated_ticks_merged(state: QuorumState, d: DissemState, merge_state,
     merge, then apply the commit gate. Returns
     (state, d, merge_state, merged, merged_count, committed_count)."""
     max_entries = _resolve_max_entries(max_entries, order_budget)
-    vtick = jax.vmap(functools.partial(
-        jaxsim.engine_tick_packed, diss_majority=diss_majority,
-        seq_majority=seq_majority, order_budget=order_budget))
 
     def body(carry, tv):
         st, d, ms, dropped = carry
         a, h, v = tv
-        d, _ = absorb_holds_packed(d, h, stab_majority)
-        st, out = vtick(st, a, _gated_votes(d, v))
+        st, d, out = _gated_step(st, d, a, h, v,
+                                 diss_majority=diss_majority,
+                                 seq_majority=seq_majority,
+                                 stab_majority=stab_majority,
+                                 order_budget=order_budget)
         entries, counts, d_t = merge_mod.entries_from_assigned(
             out["assigned"], slot_ids, max_entries)
         ms = merge_mod.append_entries(ms, entries, counts)
@@ -542,34 +563,35 @@ def gated_recycle_groups(gs: GatedRecycleState, *, watermark: int,
 
     ``id_base`` overrides the per-row fresh-id range base exactly as in
     :func:`recycle_groups` (the meshed engine's global-offset hook)."""
-    G = gs.rs.slot_ids.shape[0]
-    free = jnp.sum(~gs.rs.q.decided, axis=1, dtype=jnp.int32)
-    head_retirable = jnp.any(
-        (gs.rs.q.instance == gs.rs.retired[:, None]) & gs.rs.q.decided,
-        axis=1)
-    enable = (free < watermark) & head_retirable
-    if id_base is None:
-        id_base = jnp.arange(G, dtype=jnp.int32) * id_stride
+    with jax.named_scope(stages.RECYCLE):
+        G = gs.rs.slot_ids.shape[0]
+        free = jnp.sum(~gs.rs.q.decided, axis=1, dtype=jnp.int32)
+        head_retirable = jnp.any(
+            (gs.rs.q.instance == gs.rs.retired[:, None]) & gs.rs.q.decided,
+            axis=1)
+        enable = (free < watermark) & head_retirable
+        if id_base is None:
+            id_base = jnp.arange(G, dtype=jnp.int32) * id_stride
 
-    def compact(gs):
-        def per_group(q, ids, retired, base, en, holds, stab):
-            plan = jaxsim.compaction_plan(q, retired, en)
-            q, ids, retired, n_ret = jaxsim.compact_and_refill_packed(
-                q, ids, retired, base, plan=plan)
-            holds = jaxsim.apply_compaction(plan, holds, jnp.uint32(0))
-            stab = jaxsim.apply_compaction(plan, stab, fresh_stable)
-            return q, ids, retired, n_ret, holds, stab
-        q, ids, retired, n_ret, holds, stab = jax.vmap(per_group)(
-            gs.rs.q, gs.rs.slot_ids, gs.rs.retired, id_base, enable,
-            gs.d.hold_bits, gs.d.stable)
-        return (GatedRecycleState(
-            rs=RecycleState(q=q, slot_ids=ids, retired=retired),
-            d=DissemState(hold_bits=holds, stable=stab)), n_ret)
+        def compact(gs):
+            def per_group(q, ids, retired, base, en, holds, stab):
+                plan = jaxsim.compaction_plan(q, retired, en)
+                q, ids, retired, n_ret = jaxsim.compact_and_refill_packed(
+                    q, ids, retired, base, plan=plan)
+                holds = jaxsim.apply_compaction(plan, holds, jnp.uint32(0))
+                stab = jaxsim.apply_compaction(plan, stab, fresh_stable)
+                return q, ids, retired, n_ret, holds, stab
+            q, ids, retired, n_ret, holds, stab = jax.vmap(per_group)(
+                gs.rs.q, gs.rs.slot_ids, gs.rs.retired, id_base, enable,
+                gs.d.hold_bits, gs.d.stable)
+            return (GatedRecycleState(
+                rs=RecycleState(q=q, slot_ids=ids, retired=retired),
+                d=DissemState(hold_bits=holds, stable=stab)), n_ret)
 
-    def skip(gs):
-        return gs, jnp.zeros((G,), jnp.int32)
+        def skip(gs):
+            return gs, jnp.zeros((G,), jnp.int32)
 
-    return jax.lax.cond(jnp.any(enable), compact, skip, gs)
+        return jax.lax.cond(jnp.any(enable), compact, skip, gs)
 
 
 def _gated_recycled_body(gs: GatedRecycleState, merge_state, packed_acks,
@@ -580,11 +602,11 @@ def _gated_recycled_body(gs: GatedRecycleState, merge_state, packed_acks,
     merge → recycle both windows (same ordering rationale as
     ``_recycled_body``; holds absorb first so a recycled slot saturated
     by this tick's hold tile is already stable at vote time)."""
-    d, dout = absorb_holds_packed(gs.d, packed_holds, stab_majority)
-    vtick = jax.vmap(functools.partial(
-        jaxsim.engine_tick_packed, diss_majority=diss_majority,
-        seq_majority=seq_majority, order_budget=order_budget))
-    q, out = vtick(gs.rs.q, packed_acks, _gated_votes(d, packed_votes))
+    q, d, out = _gated_step(gs.rs.q, gs.d, packed_acks, packed_holds,
+                            packed_votes, diss_majority=diss_majority,
+                            seq_majority=seq_majority,
+                            stab_majority=stab_majority,
+                            order_budget=order_budget)
     entries, counts, dropped = merge_mod.entries_from_assigned(
         out["assigned"], gs.rs.slot_ids, max_entries)
     merge_state = merge_mod.append_entries(merge_state, entries, counts)
@@ -594,8 +616,7 @@ def _gated_recycled_body(gs: GatedRecycleState, merge_state, packed_acks,
     gs, n_ret = gated_recycle_groups(gs, watermark=watermark,
                                      id_stride=id_stride,
                                      fresh_stable=fresh_stable)
-    out = dict(out, n_retired=n_ret, newly_stable=dout["newly_stable"],
-               dropped=dropped)
+    out = dict(out, n_retired=n_ret, dropped=dropped)
     return gs, merge_state, out
 
 

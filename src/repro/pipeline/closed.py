@@ -34,6 +34,14 @@ rank's admission time and batch identity; :func:`decode_merged` maps
 the merged log back to ``(lane, seq)`` bids for the cross-validation
 against ``HTPaxosSim`` learners.
 
+Each stage runs inside a ``jax.named_scope`` named in
+``repro.pipeline.STAGES`` (``ht.gather`` ... ``ht.commit_gate``, defined
+in :mod:`repro.engine.stages`),
+so the compiled program and a device trace attribute every op to its
+stage; the engine stages carry their scopes in the engine's own
+functions. Every tick also returns boundary counters (see
+:func:`pipeline_tick`), which :func:`run_pipeline` stacks per tick.
+
 Reconfiguration is drain-then-switch at *quiescent* boundaries:
 :func:`reconfigure_pipeline` refuses to re-home in-flight ids (the
 rank addressing is per-row; a moved id would be unreachable by the
@@ -55,6 +63,7 @@ from ..engine import adaptive as adaptive_mod
 from ..engine import api
 from ..engine.api import EngineConfig, EngineState
 from ..engine.epochs import EpochTable, route_id_epoch
+from ..engine.stages import ADMISSION, GATHER, LAG_TILES
 from .vbatch import BatchState, init_batch_state, tick_flushes
 from .workload import Workload
 
@@ -248,6 +257,12 @@ def _lag_tiles(cfg: PipelineConfig, state: PipelineState)\
     return tiles(cfg.ack_lag), tiles(cfg.vote_lag), tiles(cfg.hold_lag)
 
 
+# the int32 per-tick counts of pipeline_tick's out (run_pipeline stacks
+# each over the scanned ticks)
+COUNTERS = ("flushed", "admitted", "dropped", "requests", "ordered",
+            "stable", "decided")
+
+
 def pipeline_tick(cfg: PipelineConfig, state: PipelineState,
                   arrived: jax.Array, sizes: jax.Array,
                   route_table: jax.Array)\
@@ -255,11 +270,30 @@ def pipeline_tick(cfg: PipelineConfig, state: PipelineState,
     """One tick through all four stages. ``arrived``/``sizes`` are one
     row of the workload arrays (bool[C] / int32[C]); ``route_table`` is
     :func:`build_route_table` for the current epoch. Trace-safe with
-    ``cfg`` static (see ``pipeline_tick_jit``)."""
+    ``cfg`` static (see ``pipeline_tick_jit``).
+
+    ``out`` holds this tick's counts at the stage boundaries, int32
+    scalars (:data:`COUNTERS`), and ``overflowed`` (bool, cumulative):
+
+    * ``flushed``: batches the batcher flushed;
+    * ``admitted``: flushed batches routed to a group;
+    * ``dropped``: ordered ids the merge append could not hold;
+    * ``requests``: requests in the flushed batches;
+    * ``ordered``: ids assigned an instance (order quorum);
+    * ``stable``: ids newly stable (stability quorum of the partition);
+    * ``decided``: ids newly decided (commit quorum).
+
+    The engine counts are differences of :func:`repro.engine.api.progress`
+    before and after the engine stage, so they mean the same on the
+    facade, adaptive and meshed paths. With
+    ``GatingConfig.fresh_stable`` a refilled slot counts as stable when
+    it is made."""
     G, R, D = cfg.engine.groups, cfg.capacity, cfg.n_lanes
-    idx, mask = cfg.lane_clients()
-    lane_sizes = sizes[idx].astype(jnp.int32)               # [D, K]
-    lane_valid = arrived[idx] & jnp.asarray(mask)
+    before = api.progress(cfg.engine, state.engine)
+    with jax.named_scope(GATHER):
+        idx, mask = cfg.lane_clients()
+        lane_sizes = sizes[idx].astype(jnp.int32)           # [D, K]
+        lane_valid = arrived[idx] & jnp.asarray(mask)
 
     # stage 2: byte-budget batching, linger-0 tail flush
     bstate, fl = tick_flushes(
@@ -269,39 +303,47 @@ def pipeline_tick(cfg: PipelineConfig, state: PipelineState,
     # stage 3a: admission — flatten flushes lane-major (lane order, then
     # stream position; the order a DES tick multicasts them), route each
     # bid, and scatter admission records at per-group dense ranks
-    fvalid = fl.valid.reshape(-1)                           # [N], N=D*(K+1)
-    fseq = fl.seq.reshape(-1)
-    flane = jnp.broadcast_to(jnp.arange(D, dtype=jnp.int32)[:, None],
-                             fl.valid.shape).reshape(-1)
-    seq_over = fvalid & (fseq >= cfg.seq_capacity)
-    fseq_safe = jnp.clip(fseq, 0, cfg.seq_capacity - 1)
-    fgroup = route_table[flane, fseq_safe]                  # [N]
-    onehot = (fgroup[:, None] == jnp.arange(G)) & fvalid[:, None]
-    onehot = onehot.astype(jnp.int32)                       # [N, G]
-    prior = jnp.cumsum(onehot, axis=0) - onehot
-    rank = state.admit_count[fgroup] + \
-        jnp.take_along_axis(prior, fgroup[:, None], axis=1)[:, 0]
-    cap_over = fvalid & (rank >= R)
-    ok = fvalid & ~cap_over & ~seq_over
-    g_idx = jnp.where(ok, fgroup, G)                        # G → dropped
-    r_idx = jnp.clip(rank, 0, R - 1)
-    admit_tick = state.admit_tick.at[g_idx, r_idx].set(
-        state.tick, mode="drop")
-    bid_code = state.bid_code.at[g_idx, r_idx].set(
-        flane * cfg.seq_capacity + fseq, mode="drop")
-    admit_count = state.admit_count + onehot.sum(axis=0)
-    overflowed = state.overflowed | cap_over.any() | seq_over.any()
+    with jax.named_scope(ADMISSION):
+        fvalid = fl.valid.reshape(-1)                       # [N], N=D*(K+1)
+        fseq = fl.seq.reshape(-1)
+        flane = jnp.broadcast_to(
+            jnp.arange(D, dtype=jnp.int32)[:, None],
+            fl.valid.shape).reshape(-1)
+        seq_over = fvalid & (fseq >= cfg.seq_capacity)
+        fseq_safe = jnp.clip(fseq, 0, cfg.seq_capacity - 1)
+        fgroup = route_table[flane, fseq_safe]              # [N]
+        onehot = (fgroup[:, None] == jnp.arange(G)) & fvalid[:, None]
+        onehot = onehot.astype(jnp.int32)                   # [N, G]
+        prior = jnp.cumsum(onehot, axis=0) - onehot
+        rank = state.admit_count[fgroup] + \
+            jnp.take_along_axis(prior, fgroup[:, None], axis=1)[:, 0]
+        cap_over = fvalid & (rank >= R)
+        ok = fvalid & ~cap_over & ~seq_over
+        g_idx = jnp.where(ok, fgroup, G)                    # G → dropped
+        r_idx = jnp.clip(rank, 0, R - 1)
+        admit_tick = state.admit_tick.at[g_idx, r_idx].set(
+            state.tick, mode="drop")
+        bid_code = state.bid_code.at[g_idx, r_idx].set(
+            flane * cfg.seq_capacity + fseq, mode="drop")
+        admit_count = state.admit_count + onehot.sum(axis=0)
+        overflowed = state.overflowed | cap_over.any() | seq_over.any()
 
-    state = state._replace(
-        batch=bstate, admit_count=admit_count, admit_tick=admit_tick,
-        bid_code=bid_code,
-        flushed_bytes=state.flushed_bytes
-        + jnp.where(fl.valid, fl.bytes, 0).sum(axis=1),
-        n_flushed=state.n_flushed + fl.valid.sum(axis=1, dtype=jnp.int32),
-        overflowed=overflowed)
+        state = state._replace(
+            batch=bstate, admit_count=admit_count, admit_tick=admit_tick,
+            bid_code=bid_code,
+            flushed_bytes=state.flushed_bytes
+            + jnp.where(fl.valid, fl.bytes, 0).sum(axis=1),
+            n_flushed=state.n_flushed
+            + fl.valid.sum(axis=1, dtype=jnp.int32),
+            overflowed=overflowed)
+        counts = {"flushed": fvalid.sum(dtype=jnp.int32),
+                  "admitted": onehot.sum(dtype=jnp.int32),
+                  "requests": jnp.where(fl.valid, fl.count, 0).sum(
+                      dtype=jnp.int32)}
 
     # stage 3b: delivery tiles from admission ages (live slot→id map)
-    acks, votes, holds = _lag_tiles(cfg, state)
+    with jax.named_scope(LAG_TILES):
+        acks, votes, holds = _lag_tiles(cfg, state)
 
     # stage 4: gated ordering + merge, via the facade. With
     # EngineConfig.adaptive set, the adaptive subtick variant re-absorbs
@@ -318,9 +360,9 @@ def pipeline_tick(cfg: PipelineConfig, state: PipelineState,
                                 holds=holds)
     state = state._replace(engine=estate,
                            tick=state.tick + jnp.int32(1))
-    out = {"flushed": fvalid.sum(dtype=jnp.int32),
-           "admitted": onehot.sum(dtype=jnp.int32),
-           "dropped": eout["dropped"],
+    after = api.progress(cfg.engine, estate)
+    out = {**counts, "dropped": eout["dropped"],
+           **{k: after[k] - before[k] for k in after},
            "overflowed": overflowed}
     return state, out
 
@@ -340,16 +382,13 @@ def run_pipeline(cfg: PipelineConfig, state: PipelineState,
         -> tuple[PipelineState, dict]:
     """Scan :func:`pipeline_tick` over whole workload arrays
     (bool[T, C] / int32[T, C]) in one fused jit — the end-to-end hot
-    loop the pipeline bench measures. Per-tick summaries come back
-    stacked (int32[T] each)."""
+    loop the pipeline bench measures. Per-tick counts come back stacked:
+    int32[T] for each key of :data:`COUNTERS`."""
     def step(st, xs):
         st, out = pipeline_tick(cfg, st, xs[0], xs[1], route_table)
-        return st, (out["flushed"], out["admitted"], out["dropped"])
+        return st, {k: out[k] for k in COUNTERS}
 
-    state, (flushed, admitted, dropped) = jax.lax.scan(
-        step, state, (arrived, sizes))
-    return state, {"flushed": flushed, "admitted": admitted,
-                   "dropped": dropped}
+    return jax.lax.scan(step, state, (arrived, sizes))
 
 
 def committed(cfg: PipelineConfig, state: PipelineState)\

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from ..core.network import ID_BYTES
 from ..dissem.batcher import EMPTY_BATCH_BYTES
+from ..engine import stages
 
 _NO_CAP = 1 << 30       # max_requests=None sentinel (count never reaches it)
 
@@ -140,4 +141,5 @@ def tick_flushes(state: BatchState, sizes: jax.Array, valid: jax.Array,
         lambda st, s, v: _tick_lane(st, s, v, budget_bytes=budget_bytes,
                                     max_requests=max_requests,
                                     flush_tail=flush_tail))
-    return fn(state, sizes, valid)
+    with jax.named_scope(stages.BATCHER):
+        return fn(state, sizes, valid)

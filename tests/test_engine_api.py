@@ -341,3 +341,33 @@ def test_facade_types_importable_from_package():
     assert repro.engine.Engine is Engine
     assert repro.engine.EngineConfig is EngineConfig
     assert repro.engine.EngineState is EngineState
+
+
+@pytest.mark.parametrize("family", ["plain", "recycled", "gated",
+                                    "gated_recycled"])
+def test_progress_counts_the_merge_log(family):
+    """``api.progress`` of every family: ``ordered`` is the ids in the
+    merge log, ``decided`` lies between the committed prefix and
+    ``ordered``, and only gated families count ``stable``."""
+    gated = family.startswith("gated")
+    recycling = RecyclingConfig(watermark=4, id_stride=STRIDE) \
+        if "recycled" in family else None
+    cfg = EngineConfig(groups=G, window=W, n_diss=D, n_seq=SQ,
+                       order_budget=B, merge_capacity=T * B,
+                       diss_majority=DM, seq_majority=SM,
+                       recycling=recycling,
+                       gating=GatingConfig(stab_majority=STAB)
+                       if gated else None)
+    assert cfg.family == family
+    st = api.create_state(cfg)
+    assert {k: int(v) for k, v in api.progress(cfg, st).items()} == \
+        dict(ordered=0, decided=0, **({"stable": 0} if gated else {}))
+    st, merged, count, com = api.run(cfg, st, *tiles(5, holds=gated))
+    got = {k: int(v) for k, v in api.progress(cfg, st).items()}
+    assert set(got) == {"ordered", "decided"} | ({"stable"} if gated
+                                                 else set())
+    real = np.asarray(merged)[:int(count)] >= 0
+    assert got["ordered"] == int(real.sum()) > 0
+    assert int(real[:int(com)].sum()) <= got["decided"] <= got["ordered"]
+    if gated:
+        assert got["decided"] <= got["stable"]
