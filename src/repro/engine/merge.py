@@ -74,16 +74,32 @@ def append_entries(state: MergeState, entries: jax.Array,
     longer *silently* dropped: the per-group overflow count accumulates in
     ``state.overflowed`` so callers (and the run_* debug asserts) can
     detect an undersized log instead of consuming a corrupted order.
+
+    Cost follows K, not the capacity L: each group rewrites only the
+    W = min(K, L) cells starting at ``clip(watermark, 0, L − W)``, a window
+    that holds every cell ``[watermark, watermark + counts) ∩ [0, L)`` the
+    append can touch. Where K ≥ L the window is the whole row.
     """
     with jax.named_scope(stages.MERGE_APPEND):
         G, L = state.logs.shape
         K = entries.shape[1]
-        j = jnp.arange(L, dtype=jnp.int32)[None, :]                  # [1, L]
-        rel = j - state.watermarks[:, None]                          # [G, L]
+        W = min(K, L)
+        start = jnp.clip(state.watermarks, 0, L - W)                 # [G]
+        # one dynamic slice per group (G is static): vmapped, the per-row
+        # starts would lower to a gather and a scatter
+        old = jnp.concatenate([
+            jax.lax.dynamic_slice(state.logs, (g, start[g]), (1, W))
+            for g in range(G)])                                      # [G, W]
+        k = jnp.arange(W, dtype=jnp.int32)[None, :]                  # [1, W]
+        rel = start[:, None] + k - state.watermarks[:, None]         # [G, W]
         take = (rel >= 0) & (rel < counts[:, None])
         gathered = jnp.take_along_axis(
             entries, jnp.clip(rel, 0, K - 1), axis=1)
-        logs = jnp.where(take, gathered, state.logs)
+        new = jnp.where(take, gathered, old)
+        logs = state.logs
+        for g in range(G):
+            logs = jax.lax.dynamic_update_slice(
+                logs, new[g:g + 1], (g, start[g]))
         counts = counts.astype(jnp.int32)
         # entries whose cell index wm+k lands at or past L (watermark may
         # already exceed L from earlier overflow, hence the clip to

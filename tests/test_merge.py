@@ -4,6 +4,7 @@ pure-Python oracle, and is invariant under tick batching (the same entry
 streams appended in different chunkings yield the same merged prefix)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -193,3 +194,29 @@ def test_merged_command_log_replicas_agree():
     # conflicting re-decision of an instance must raise (Paxos safety)
     with pytest.raises(AssertionError):
         log1.feed(0, 0, Command("STEP", "other"))
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def test_append_entries_writes_only_k_wide_windows():
+    """The append's cost follows K, not the log's capacity: at G=4,
+    L=4096, K=512 the only equations with a whole-log [G, L] output are
+    the per-group ``dynamic_update_slice`` writes — no gather or select
+    over all G·L cells."""
+    G, L, K = 4, 4096, 512
+    jaxpr = jax.make_jaxpr(M.append_entries)(
+        M.init_merge(G, L), jnp.zeros((G, K), jnp.int32),
+        jnp.full((G,), K, jnp.int32))
+    whole_log = [eqn.primitive.name for eqn in _eqns(jaxpr.jaxpr)
+                 if any(getattr(v.aval, "shape", None) == (G, L)
+                        for v in eqn.outvars)]
+    assert whole_log == ["dynamic_update_slice"] * G
