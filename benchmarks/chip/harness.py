@@ -7,6 +7,8 @@ A cell of ``BENCHMARK.json`` names a deployment (``configs/<config>.json``)
 and a traffic mix (``traffic/<traffic>.json``); per-layer metrics are read
 by ``metrics/<name>.py`` and end-to-end metrics by ``end_to_end/<name>.py``.
 Adding a cell, a mix or a metric adds files; this one does not change.
+A deployment's ``mesh_devices`` (the group-sharded engine, ``system.py``)
+is at most its cell's ``chips``: the run then fails on a host with fewer.
 
 A run builds the deployment, warms up every shape the cell uses (set-up),
 then drives the program for ``--seconds``: chunks of ticks through
@@ -36,7 +38,6 @@ import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import shutil  # noqa: E402
-import statistics  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
 
@@ -44,8 +45,6 @@ HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent.parent
 CACHE = ROOT / ".bench_cache"
 TRACE_DIR = ROOT / ".bench_trace"
-SPAN_MIN_S = 0.25       # a host-clock span covers at least this long
-SPAN_BLOCKS = 3
 
 
 def fail(msg: str):
@@ -179,31 +178,6 @@ class Run:
     def __init__(self, **kw):
         self.__dict__.update(kw)
 
-    def per_call_ms(self, make_call) -> float:
-        """Median over blocks of the host time per call, synced at the end
-        of each block; every block spans at least ``SPAN_MIN_S`` and gets
-        a fresh ``call = make_call()`` (built outside the timing)."""
-        import jax
-        call = make_call()
-        jax.block_until_ready(call())
-        t = now()
-        jax.block_until_ready(call())
-        n = max(1, math.ceil(SPAN_MIN_S / max(now() - t, 1e-6)))
-        per = []
-        for _ in range(SPAN_BLOCKS):
-            call = make_call()
-            t = now()
-            for _ in range(n):
-                out = call()
-            jax.block_until_ready(out)
-            per.append((now() - t) / n * 1e3)
-        return statistics.median(per)
-
-    def state_copy(self):
-        """A copy of the pipeline state kept at the traced segment's last
-        arrival tick (calls that donate their state take a copy)."""
-        return copy_tree(self.state)
-
 
 def copy_tree(tree):
     """A device copy of every array of ``tree``, synced."""
@@ -212,14 +186,18 @@ def copy_tree(tree):
 
 
 def warm_up(window: Window) -> None:
-    """Run every program and traffic shape the cell uses once: a chunk,
-    a drain tick and the reads, under each delay profile."""
+    """Run every program and traffic shape the cell uses once: two chunks,
+    a drain tick and the reads, under each delay profile. The second chunk
+    takes a state as a chunk leaves it: on a device mesh that state is
+    placed otherwise than a fresh one, and ``run_pipeline`` compiles for
+    each placement."""
     import jax
     prog, traffic = window.prog, window.traffic
     skey = traffic.segment_key(window.key, 0)
     for k in range(len(prog.cfgs)):
         state = prog.init(k)
-        state, _ = prog.run_chunk(state, *traffic.chunk(skey, 0))
+        for t in (0, traffic.chunk_ticks):
+            state, _ = prog.run_chunk(state, *traffic.chunk(skey, t))
         state, _ = prog.tick(state, *prog.no_arrivals)
         jax.block_until_ready((prog.committed(state), prog.admitted(state),
                                prog.record(state,
@@ -315,9 +293,12 @@ def run_cell(bench: dict, name: str, seed: int, seconds: float,
     if trace:
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         # the state copy kept in the traced phase compiles its copy
-        # programs here, so the trace holds no host time for them; the
-        # fresh state is the traced segment's own (same profile)
-        copy_tree(prog.init(len(window.segments)))
+        # programs here, so the trace holds no host time for them: on a
+        # state of the traced segment's profile, placed as a chunk leaves it
+        rows = traffic.chunk(traffic.segment_key(window.key, 0), 0)
+        state, _ = prog.run_chunk(prog.init(len(window.segments)), *rows)
+        copy_tree(state)
+        del state
         kept = {}
         jax.profiler.start_trace(str(TRACE_DIR))
         try:

@@ -4,6 +4,17 @@ Only public entry points are driven: ``init_pipeline``, ``run_pipeline``
 (a chunk of ticks), ``pipeline_tick_jit`` (one tick), ``committed`` (the
 merge and commit gate) and ``build_route_table``. The deployment file sets
 every size and the per-node delays (``node_lags``).
+
+A deployment may ask for the group-sharded engine with ``mesh_devices``
+(an int): the engine's G groups are then split over that many devices,
+G / ``mesh_devices`` to each (``EngineConfig(mesh=MeshConfig(n_devices=
+mesh_devices))``, ``repro.engine.meshed``). Without the key the engine runs
+on one device. ``Program`` refuses a deployment whose ``mesh_devices`` JAX
+cannot give it or that does not divide ``groups``, where the program's own
+mesh would quietly use fewer devices or pad the groups. The jitted entry
+points get the same arguments on both paths: the route table, the empty
+row and the traffic rows stay uncommitted, on the default device, and
+``jit`` places them on the mesh as the sharded program needs.
 """
 from __future__ import annotations
 
@@ -36,13 +47,31 @@ def node_lags(dep: dict) -> list[dict]:
     return out
 
 
+def mesh_devices(dep: dict) -> int | None:
+    """The deployment's ``mesh_devices``, or None without the key; a value
+    that is not a whole number from 1 up dividing ``groups`` is an error."""
+    n = dep.get("mesh_devices")
+    if n is None:
+        return None
+    if isinstance(n, bool) or not isinstance(n, int) or n < 1:
+        raise ValueError(f"mesh_devices must be a whole number >= 1, "
+                         f"got {n!r}")
+    if dep["groups"] % n:
+        raise ValueError(f"mesh_devices={n} does not divide groups="
+                         f"{dep['groups']}: every device holds as many "
+                         f"groups")
+    return n
+
+
 def pipeline_config(dep: dict, lags: dict, gate_open: bool = False):
     """The program's configuration of the deployment under one delay
     profile. ``gate_open`` seeds every id stable, which switches the
     stability gate off (the program's ungated path; a fault here)."""
-    from repro.engine.api import EngineConfig, GatingConfig, RecyclingConfig
+    from repro.engine.api import (EngineConfig, GatingConfig, MeshConfig,
+                                  RecyclingConfig)
     from repro.pipeline import PipelineConfig
     ticks = dep["segment_ticks"] + dep["drain_ticks_max"]
+    n_mesh = mesh_devices(dep)
     return PipelineConfig(
         engine=EngineConfig(
             groups=dep["groups"], window=dep["window"],
@@ -53,7 +82,8 @@ def pipeline_config(dep: dict, lags: dict, gate_open: bool = False):
                                       id_stride=dep["admission_capacity"]),
             gating=GatingConfig(
                 n_diss_partition=dep["disseminators"] // dep["groups"],
-                pre_stable=gate_open, fresh_stable=gate_open)),
+                pre_stable=gate_open, fresh_stable=gate_open),
+            mesh=None if n_mesh is None else MeshConfig(n_devices=n_mesh)),
         n_clients=dep["clients"], budget_bytes=dep["batch_budget_bytes"],
         ack_lag=tuple(int(x) for x in lags["ack"]),
         hold_lag=tuple(int(x) for x in lags["hold"]),
@@ -93,6 +123,10 @@ class Program:
                  cache_dir: Path | None):
         from repro.pipeline import (committed, init_pipeline,
                                     pipeline_tick_jit, run_pipeline)
+        n_mesh = mesh_devices(dep)
+        if n_mesh is not None and n_mesh > len(jax.devices()):
+            raise ValueError(f"mesh_devices={n_mesh} but JAX sees "
+                             f"{len(jax.devices())} device(s)")
         self.cfgs = [pipeline_config(dep, lags, self.gate_open)
                      for lags in profiles]
         if len({c.engine for c in self.cfgs}) != 1:
